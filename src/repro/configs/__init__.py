@@ -2,7 +2,9 @@
 
 ``get_config(arch_id)`` returns the full assigned configuration;
 ``get_smoke_config(arch_id)`` returns a reduced same-family configuration
-for CPU smoke tests (small layers/width, few experts, tiny vocab).
+for CPU smoke tests (small layers/width, few experts, tiny vocab);
+``scaled_config(arch_id, scale)`` picks one of the three by name for the
+serve and train entry points.
 """
 
 from __future__ import annotations
@@ -55,3 +57,25 @@ def get_smoke_config(arch_id: str):
 
 def all_configs():
     return {a: get_config(a) for a in ARCH_IDS}
+
+
+SCALES = ("smoke", "100m", "full")
+
+
+def scaled_config(arch_id: str, scale: str):
+    """``full``: the published widths; ``smoke``: the CPU smoke config;
+    ``100m``: a same-family ~100M-parameter reduction."""
+    if scale == "full":
+        return get_config(arch_id)
+    if scale == "smoke":
+        return get_smoke_config(arch_id)
+    if scale == "100m":
+        import jax.numpy as jnp
+
+        cfg = get_smoke_config(arch_id)
+        return dataclasses.replace(
+            cfg, n_layers=8, d_model=512, n_heads=8,
+            n_kv_heads=min(8, max(1, cfg.n_kv_heads)), head_dim=64,
+            d_ff=2048, vocab=32768, loss_chunk=256,
+            param_dtype=jnp.float32, compute_dtype=jnp.float32, remat=False)
+    raise ValueError(f"unknown scale {scale!r}; have {SCALES}")

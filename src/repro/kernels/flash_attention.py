@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import default_interpret
+
 NEG_INF = -2.0e38
 
 
@@ -65,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("bq", "bkv", "window", "interpret"))
 def flash_attention(q, k, v, *, window: int = 0, bq: int = 128, bkv: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Causal (optionally windowed) attention.
 
     q, k, v: (BH, S, d) with matching S (self-attention).  Returns (BH, S, d).
@@ -92,5 +94,5 @@ def flash_attention(q, k, v, *, window: int = 0, bq: int = 128, bkv: int = 128,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(q, k, v)

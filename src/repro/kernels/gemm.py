@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import default_interpret
+
 
 def _gemm_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, nk: int, accumulate: bool):
     k = pl.program_id(2)
@@ -46,7 +48,7 @@ def _gemm_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, nk: int, accumulate: bo
 @functools.partial(
     jax.jit, static_argnames=("bm", "bn", "bk", "accumulate", "interpret"))
 def gemm(a, b, c=None, *, bm: int = 128, bn: int = 128, bk: int = 128,
-         accumulate: bool = False, interpret: bool = True):
+         accumulate: bool = False, interpret: bool | None = None):
     """C = A @ B  (+ C_in if accumulate).  Shapes must tile evenly."""
     M, K = a.shape
     K2, N = b.shape
@@ -69,5 +71,5 @@ def gemm(a, b, c=None, *, bm: int = 128, bn: int = 128, bk: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(a, b, c)

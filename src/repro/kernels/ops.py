@@ -1,24 +1,15 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container validates kernel
-bodies in interpreter mode); on a TPU backend the same calls compile to
-Mosaic.  ``use_kernels(cfg)`` gates kernel usage per model config.
+The backend choice (Mosaic on a TPU, the interpreter elsewhere) lives in
+``kernels/backend.py``; ``kernels/widths.py`` holds each kernel at the
+widths of the models it serves.
 """
 
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import default_interpret, on_tpu  # noqa: F401
 from repro.kernels.flash_attention import flash_attention  # noqa: F401
 from repro.kernels.gemm import gemm  # noqa: F401
 from repro.kernels.reduce_nway import reduce_nway  # noqa: F401
 from repro.kernels.rglru import rglru_scan  # noqa: F401
 from repro.kernels.rwkv6 import wkv  # noqa: F401
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def default_interpret() -> bool:
-    return not on_tpu()

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run driver.
 
 For every (architecture x applicable shape) cell:
@@ -19,6 +16,7 @@ resumable.  Usage:
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import time
 import traceback
@@ -190,6 +188,9 @@ def _done(records, arch, shape, mesh, variant) -> bool:
 
 
 def main():
+    # the placeholder production mesh needs 512 host devices; set before
+    # JAX creates its backends, and only when run as a script
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Perf hill-climb driver: re-lower chosen cells with candidate changes.
 
 Each variant is one hypothesis from the §Perf log; results append to
@@ -13,6 +10,7 @@ the baseline.
 
 import argparse
 import dataclasses
+import os
 
 from repro.configs import get_config
 from repro.launch.dryrun import append_result, run_cell
@@ -58,6 +56,9 @@ VARIANTS = {
 
 
 def main():
+    # the placeholder production mesh needs 512 host devices; set before
+    # JAX creates its backends, and only when run as a script
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", required=True, help="arch:shape")
     ap.add_argument("--variant", required=True, choices=list(VARIANTS))

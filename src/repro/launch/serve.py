@@ -1,7 +1,10 @@
 """End-to-end serving driver: batched generation over a request queue.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1_5_0_5b \
-      --requests 8 --max-new 16
+      --requests 8 --max-new 16 [--scale full]
+
+``--scale`` picks the same config family as launch/train.py: ``smoke``
+(the default, CPU-sized), ``100m`` or ``full`` (the published widths).
 """
 
 from __future__ import annotations
@@ -11,7 +14,8 @@ import time
 
 import jax
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, SCALES, scaled_config
+from repro.launch.cache import use_compile_cache
 from repro.models import get_family
 from repro.runtime.server import Request, Server
 
@@ -19,6 +23,7 @@ from repro.runtime.server import Request, Server
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1_5_0_5b", choices=ARCH_IDS)
+    ap.add_argument("--scale", default="smoke", choices=SCALES)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--batch-slots", type=int, default=4)
@@ -27,7 +32,8 @@ def main():
     ap.add_argument("--ckpt-dir", default=None, help="restore params from checkpoint")
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch)
+    use_compile_cache()
+    cfg = scaled_config(args.arch, args.scale)
     fam = get_family(cfg)
     params = fam.init(jax.random.PRNGKey(0), cfg)
     if args.ckpt_dir:

@@ -186,7 +186,7 @@ def build_cell(cfg: ModelConfig, shape_name: str, mesh,
                        "v": jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), p)},
             p_shapes)
 
-        from repro.models.common import constrain as _constrain
+        from repro.sharding import constrain as _constrain
 
         grad_specs = o_specs["m"]  # ZeRO sharding for the f32 accumulator
 

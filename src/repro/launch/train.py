@@ -3,9 +3,9 @@
   PYTHONPATH=src python -m repro.launch.train --arch qwen1_5_0_5b \
       --steps 200 --batch 8 --seq 256 --scale 100m --ckpt-dir /tmp/ck
 
-``--scale`` picks a same-family reduction of the assigned config sized for
-this host (smoke ~1M params, 100m ~100M params); the full assigned configs
-are exercised via launch/dryrun.py on the production mesh.
+``--scale`` picks the config (``repro.configs.scaled_config``): ``smoke``
+(~1M params), ``100m`` (a same-family ~100M-param reduction) or ``full``
+(the published widths).
 """
 
 from __future__ import annotations
@@ -15,33 +15,17 @@ import dataclasses
 
 import jax
 
-from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.configs import ARCH_IDS, SCALES, scaled_config
 from repro.data import ByteFileSource, SyntheticLMSource
+from repro.launch.cache import use_compile_cache
 from repro.optim import AdamWConfig
 from repro.runtime import Trainer, TrainerConfig
-
-
-def scaled_config(arch: str, scale: str):
-    if scale == "full":
-        return get_config(arch)
-    if scale == "smoke":
-        return get_smoke_config(arch)
-    if scale == "100m":
-        cfg = get_smoke_config(arch)
-        import jax.numpy as jnp
-
-        return dataclasses.replace(
-            cfg, n_layers=8, d_model=512, n_heads=8,
-            n_kv_heads=min(8, max(1, cfg.n_kv_heads)), head_dim=64,
-            d_ff=2048, vocab=32768, loss_chunk=256,
-            param_dtype=jnp.float32, compute_dtype=jnp.float32, remat=False)
-    raise ValueError(scale)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1_5_0_5b", choices=ARCH_IDS)
-    ap.add_argument("--scale", default="100m", choices=["smoke", "100m", "full"])
+    ap.add_argument("--scale", default="100m", choices=SCALES)
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
@@ -52,6 +36,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = scaled_config(args.arch, args.scale)
     if args.data:
         src = ByteFileSource(args.data, seq_len=args.seq, global_batch=args.batch,

@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.sharding import constrain  # noqa: F401  (the model modules import it from here)
+
 
 # ---------------------------------------------------------------------------
 # Configuration
@@ -214,16 +216,6 @@ class ShardingPolicy:
 
 
 REPLICATED = ShardingPolicy(batch_axes=(), model_axis=None)
-
-
-def constrain(x, spec: Optional[P]):
-    """Apply a sharding constraint if running under a mesh; no-op otherwise."""
-    if spec is None:
-        return x
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):
-        return x  # no mesh in scope (pure-CPU smoke tests)
 
 
 # ---------------------------------------------------------------------------

@@ -10,11 +10,14 @@ the paper's vocabulary).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from repro.sharding import constrain
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,14 +62,7 @@ def adamw_update(params, grads, state, cfg: AdamWConfig, lr_scale=1.0,
     lr = cfg.lr * lr_scale
 
     def upd(p, g, m, v, spec):
-        def shard(x):
-            if spec is None:
-                return x
-            try:
-                return jax.lax.with_sharding_constraint(x, spec)
-            except (ValueError, RuntimeError):
-                return x
-
+        shard = partial(constrain, spec=spec)
         g = shard(g.astype(jnp.float32) * scale)
         m = cfg.b1 * m + (1 - cfg.b1) * g
         v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)

@@ -25,11 +25,13 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.models import get_family
 from repro.models.common import ModelConfig, REPLICATED, ShardingPolicy
 from repro.optim import AdamWConfig, adamw_init, adamw_update, compressed_mean, warmup_cosine
+from repro.runtime.elastic import reshard
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,8 +102,6 @@ class Trainer:
         if tcfg.compress_grads and tcfg.dp_axis and self.mesh is not None:
             # run the whole step under shard_map on the DP axis so the int8
             # payload is what actually crosses the fabric
-            from jax.sharding import PartitionSpec as P
-
             spec_rep = P()
             batch_spec = P(tcfg.dp_axis)
             mapped = partial(
@@ -117,6 +117,13 @@ class Trainer:
     def init_state(self, rng):
         params = self.family.init(rng, self.model_cfg)
         opt_state = adamw_init(params)
+        if self.mesh is not None:
+            # place the state on the mesh by the policy's specs, so a
+            # model-sharded step holds 1/TP of each sharded tensor per device
+            specs = self.family.param_specs(self.model_cfg, self.policy)
+            params = reshard(params, specs, self.mesh)
+            opt_state = reshard(opt_state, {"step": P(), "m": specs, "v": specs},
+                                self.mesh)
         err_state = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params) \
             if self.tcfg.compress_grads else jax.tree.map(lambda p: jnp.zeros((1,)), params)
         return params, opt_state, err_state

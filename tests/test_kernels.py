@@ -1,8 +1,11 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + hypothesis.
 
-All kernels run in interpret mode (CPU container); the same pallas_call
-lowers to Mosaic on TPU.
+Off the TPU the kernels' ``interpret=None`` default resolves to the Pallas
+interpreter; on a TPU the same pallas_call lowers to Mosaic
+(tests/test_tpu_compile.py compiles them for a described v5e).
 """
+
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -164,3 +167,40 @@ def test_wkv_matches_model_chunked():
     out_k = out_k.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_model),
                                rtol=2e-3, atol=2e-3)
+
+
+# -- backend-chosen interpret mode and the shapes Mosaic once refused -----------
+
+KERNELS = {"flash_attention": flash_attention, "gemm": gemm,
+           "reduce_nway": reduce_nway, "rglru_scan": rglru_scan, "wkv": wkv}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_interpret_default_is_chosen_by_backend(name):
+    from repro.kernels.ops import default_interpret
+
+    assert inspect.signature(KERNELS[name]).parameters["interpret"].default is None
+    assert default_interpret() == (jax.default_backend() != "tpu")
+
+
+# the model-width cases of kernels/widths.py at the shapes Mosaic once
+# refused, run by the interpreter with element-wise tolerances:
+#   rglru_scan  width 2560 (RecurrentGemma-2B), chunk 128: the in-kernel
+#               associative_scan made a zero-size slice
+#   wkv         BH=40 heads (RWKV6-3B), hd=64: the (1, hd) block of u broke
+#               the second-minor tiling rule
+#   reduce_nway 4 x 8192 f32 with bs=512: the 1-D (512,) out block did not
+#               match the TPU layout of f32[8192]
+ONCE_REFUSED = {"rglru_scan": ("rglru_scan", 1e-4), "wkv": ("wkv", 2e-3),
+                "reduce_nway": ("reduce_nway_add", 1e-5)}
+
+
+@pytest.mark.parametrize("name", list(ONCE_REFUSED))
+def test_kernel_at_once_refused_shape(name):
+    from repro.kernels.widths import MODEL_WIDTH
+
+    case, tol = MODEL_WIDTH[ONCE_REFUSED[name][0]], ONCE_REFUSED[name][1]
+    args = case.make_inputs(jax.random.PRNGKey(3))
+    out, expected = case.kernel(*args), case.reference(*args)
+    assert out.shape == expected.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected), rtol=tol, atol=tol)
