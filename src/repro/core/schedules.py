@@ -23,11 +23,18 @@ on the production mesh (see launch/roofline).
 
 All schedules assume a power-of-two axis size, matching the paper's
 (dst, mask) submesh constraint (Section 3.2.2) — enforced here.
+
+Each public collective traces its body under the named scope
+``schedules.<op>.<schedule>`` (for example ``schedules.all_reduce.tree``).
+The scope is metadata only: it enters the ``op_name`` of every HLO op the
+schedule emits, and from there the device trace, so a profile credits each
+permute, add, select and copy to the schedule that ran it.
 """
 
 from __future__ import annotations
 
-from functools import partial
+import functools
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -54,11 +61,26 @@ def _xor_perm(n: int, mask: int):
     return [(i, i ^ mask) for i in range(n)]
 
 
+def _scoped(fn):
+    """Runs the collective ``fn`` under ``schedules.<fn name>.<schedule>``."""
+    sig = inspect.signature(fn)
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        bound = sig.bind(*args, **kwargs)
+        bound.apply_defaults()
+        with jax.named_scope(f"schedules.{fn.__name__}.{bound.arguments['schedule']}"):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
 # ---------------------------------------------------------------------------
 # Broadcast (paper: multicast)
 # ---------------------------------------------------------------------------
 
 
+@_scoped
 def broadcast(x, axis: str, root: int = 0, schedule: str = "native", chunks: int = 1):
     """Broadcast ``x`` from ``root`` along ``axis`` to all members."""
     n = _axis_size(axis)
@@ -118,6 +140,7 @@ def _broadcast_tree(x, axis, root, n, idx):
 # ---------------------------------------------------------------------------
 
 
+@_scoped
 def all_reduce(x, axis: str, schedule: str = "native", chunks: int = 1):
     n = _axis_size(axis)
     _check_pow2(n, "all_reduce")
@@ -175,6 +198,7 @@ def _ring_all_reduce(x, axis, n):
 # ---------------------------------------------------------------------------
 
 
+@_scoped
 def all_gather(x, axis: str, schedule: str = "native"):
     """Gather shards along a new leading dim -> concatenated on dim 0."""
     n = _axis_size(axis)
@@ -207,6 +231,7 @@ def all_gather(x, axis: str, schedule: str = "native"):
     raise ValueError(f"unknown schedule {schedule!r}")
 
 
+@_scoped
 def reduce_scatter(x, axis: str, schedule: str = "native"):
     """Sum over the axis, scattering dim 0: (m, ...) -> (m/n, ...).
 
@@ -226,6 +251,7 @@ def reduce_scatter(x, axis: str, schedule: str = "native"):
     return carry
 
 
+@_scoped
 def barrier(axis: str, schedule: str = "native"):
     """LsbAnd-analogue barrier: a 1-element reduction over the axis."""
     token = jnp.ones((), jnp.int32)
