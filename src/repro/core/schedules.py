@@ -19,7 +19,10 @@ The native schedules lower to single XLA collectives (executed by the ICI
 fabric — the TPU analogue of the paper's in-network support); the software
 schedules lower to ``collective-permute`` chains whose total traffic is
 visible in the compiled HLO, which is how the HW-vs-SW comparison is made
-on the production mesh (see launch/roofline).
+on the production mesh (see launch/roofline).  Each permute of a software
+broadcast carries only the schedule's real unicasts, the (src, dst) pairs
+``broadcast_ops`` emits: one pair per chain hop, 2^i pairs at tree stage
+i.  The reductions' permutes carry data on every pair (full ring or XOR).
 
 All schedules assume a power-of-two axis size, matching the paper's
 (dst, mask) submesh constraint (Section 3.2.2) — enforced here.
@@ -35,6 +38,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -82,7 +86,11 @@ def _scoped(fn):
 
 @_scoped
 def broadcast(x, axis: str, root: int = 0, schedule: str = "native", chunks: int = 1):
-    """Broadcast ``x`` from ``root`` along ``axis`` to all members."""
+    """Broadcast ``x`` from ``root`` along ``axis`` to all members.
+
+    The software schedules build their permute pairs from ``root``, so for
+    them it must be a static integer; a traced one raises ``TypeError``.
+    """
     n = _axis_size(axis)
     _check_pow2(n, "broadcast")
     idx = jax.lax.axis_index(axis)
@@ -90,6 +98,7 @@ def broadcast(x, axis: str, root: int = 0, schedule: str = "native", chunks: int
         # In-network multicast: one fabric-level collective.
         masked = jnp.where(idx == root, x, jnp.zeros_like(x))
         return jax.lax.psum(masked, axis)
+    root = _static_root(root, n)
     if schedule == "chain":
         return _broadcast_chain(x, axis, root, n, idx, chunks=1)
     if schedule == "pipelined":
@@ -99,37 +108,48 @@ def broadcast(x, axis: str, root: int = 0, schedule: str = "native", chunks: int
     raise ValueError(f"unknown schedule {schedule!r}")
 
 
+def _static_root(root, n: int) -> int:
+    try:
+        return operator.index(root) % n
+    except TypeError as e:
+        raise TypeError(
+            f"broadcast: a software schedule needs a static integer root, got "
+            f"{root!r}; its permute pairs are fixed when the program is traced") from e
+
+
 def _broadcast_chain(x, axis, root, n, idx, chunks: int):
     """Neighbour chain from the root (Eq 1); ``chunks>1`` pipelines it (Eq 2).
 
-    Executes n-1 ppermute steps per chunk; chunk c's step s moves the chunk
-    from relative position s to s+1.  SPMD-uniform: every device runs every
-    step; non-participants forward zeros that are masked out.
+    Each chunk runs its own chain of n-1 permutes.  Hop s carries the single
+    pair (root+s-1, root+s), the unicast ``broadcast_ops`` emits for it; the
+    receiver adds the copy to the zeros it held, and every other member adds
+    the zeros a permute gives to devices it does not target.
     """
-    rel = (idx - root) % n  # my distance down the chain
     parts = jnp.split(x, chunks, axis=0) if chunks > 1 else [x]
     out_parts = []
-    perm = _ring_perm(n)
     for part in parts:
-        have = jnp.where(rel == 0, part, jnp.zeros_like(part))
-        acc = have
-        for _ in range(n - 1):
-            have = jax.lax.ppermute(have, axis, perm)
-            acc = acc + have  # each device receives its copy exactly once
+        acc = jnp.where(idx == root, part, jnp.zeros_like(part))
+        for s in range(1, n):
+            hop = [((root + s - 1) % n, (root + s) % n)]
+            acc = acc + jax.lax.ppermute(acc, axis, hop)
         out_parts.append(acc)
     return jnp.concatenate(out_parts, axis=0) if chunks > 1 else out_parts[0]
 
 
 def _broadcast_tree(x, axis, root, n, idx):
-    """Recursive-doubling broadcast (Eq 3): log2(n) ppermute stages."""
+    """Recursive-doubling broadcast (Eq 3): log2(n) ppermute stages.
+
+    Stage i carries the 2^i pairs (root+j, root+j+2^i), j < 2^i: each
+    member that holds the value sends it to one that does not.
+    """
     rel = (idx - root) % n
     have = jnp.where(rel == 0, x, jnp.zeros_like(x))
     stages = n.bit_length() - 1
     for i in range(stages):
         dist = 1 << i
-        perm = [(j, (j + dist) % n) for j in range(n)]
+        perm = [((root + j) % n, (root + j + dist) % n) for j in range(dist)]
         recv = jax.lax.ppermute(have, axis, perm)
-        # devices with rel >= dist receive from rel - dist
+        # devices with rel in [dist, 2 dist) receive from rel - dist
         have = jnp.where((rel >= dist) & (rel < 2 * dist), recv, have)
     return have
 

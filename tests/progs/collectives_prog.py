@@ -29,15 +29,25 @@ def run_spmd(fn, *args, in_specs, out_specs):
 
 
 def check_broadcast():
-    x = jnp.arange(N * 4 * 6, dtype=jnp.float32).reshape(N * 4, 6)
+    x = jnp.arange(N * 4 * 6, dtype=jnp.float32).reshape(N * 4, 6)  # integers: exact
     for schedule in ("native", "chain", "pipelined", "tree"):
-        for root in (0, 3):
+        for root in range(N):
             out = run_spmd(
                 lambda xs: sched.broadcast(xs, "x", root=root, schedule=schedule, chunks=2),
                 x, in_specs=(P("x", None),), out_specs=P("x", None))
             expected = np.tile(np.asarray(x).reshape(N, 4, 6)[root], (N, 1, 1)).reshape(N * 4, 6)
-            np.testing.assert_allclose(np.asarray(out), expected, rtol=1e-6,
-                                       err_msg=f"broadcast {schedule} root={root}")
+            np.testing.assert_array_equal(np.asarray(out), expected,
+                                          err_msg=f"broadcast {schedule} root={root}")
+    for schedule in ("chain", "pipelined", "tree"):  # their permute pairs need a static root
+        try:
+            run_spmd(lambda xs, r: sched.broadcast(xs, "x", root=r[0], schedule=schedule,
+                                                   chunks=2),
+                     x, jnp.ones((N,), jnp.int32), in_specs=(P("x", None), P("x")),
+                     out_specs=P("x", None))
+        except TypeError as e:
+            assert "static integer root" in str(e), e
+        else:
+            raise AssertionError(f"broadcast {schedule} took a traced root")
     print("broadcast ok")
 
 
