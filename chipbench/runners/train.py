@@ -16,7 +16,10 @@ Set-up drives the first ``checked_steps`` steps through that same call, and
 keeps what the comparison needs: their losses, the first gradient as the
 optimizer holds it (its first moment over 1 - b1) and the parameters before
 the next step.  The window goes on from there.  ``train_tokens_per_s`` is the
-tokens of the steps completed in the window over the window.
+tokens of the steps completed in the window over the window.  The notes also
+give the compiled step's ``memory_analysis()`` and the chip's
+``memory_stats()`` after set-up and after the window: ``memory_peak_bytes``
+reads one key of the latter.
 
 After the window the program's state is freed and the checked steps are
 compared with the plain reference (``reference/<reference>.py``), worst leaf
@@ -66,6 +69,9 @@ ARCHITECTURE = {"family": "transformer", "n_experts": 0, "attn_window": 0,
                 "local_global_ratio": 0}
 # Below this share of the median leaf's reference gradient, a leaf moves by round-off.
 STILL = 1e-3
+# The figures of the compiled step's ``memory_analysis()`` that the notes give.
+MEMORY_FIGURES = ("argument_size_in_bytes", "output_size_in_bytes", "alias_size_in_bytes",
+                  "temp_size_in_bytes", "generated_code_size_in_bytes")
 
 
 def program_config(cfg: dict, base):
@@ -238,8 +244,20 @@ class Runner:
                 self.grad1 = jax.tree.map(lambda m: m / np.float32(1 - b1), self.grad1)
         self.params_after = jax.device_get(self.params)
         self.next_step = self.traffic["checked_steps"]
+        self.memory_notes = self._memory_notes()
         self.flops_per_step = flops.train_step_flops(self.cfg, self.traffic["batch"],
                                                      self.traffic["seq_len"])
+
+    def _memory_notes(self) -> list[str]:
+        """The compiled step's own account of its memory, beside the device's
+        allocator statistics, which ``memory_peak_bytes`` reads one key of."""
+        jnp = self.jax.numpy
+        batch = {k: jnp.asarray(v) for k, v in self.batch(0).items()}
+        compiled = self.trainer._step_fn.lower(self.params, self.opt, batch, self.err).compile()
+        analysis = compiled.memory_analysis()
+        figures = {f: getattr(analysis, f, None) for f in MEMORY_FIGURES}
+        return [f"the compiled step's memory_analysis: {figures}",
+                f"device memory_stats after set-up: {self.devices[0].memory_stats()}"]
 
     def window(self, seconds: float, trace_dir=None) -> dict:
         jax = self.jax
@@ -275,7 +293,9 @@ class Runner:
                       f"host batch: {batch_s}",
                       f"checked steps' losses: {self.losses}",
                       f"model FLOPs a step: {self.flops_per_step}",
-                      "generator lateness: none, a closed loop (each step waits for the last)"],
+                      "generator lateness: none, a closed loop (each step waits for the last)",
+                      *self.memory_notes,
+                      f"device memory_stats after the window: {self.devices[0].memory_stats()}"],
         }
 
     def release(self) -> None:

@@ -67,6 +67,24 @@ def test_workload_resolves_to_its_files(workload):
         assert callable(bench.metric_reader(m["name"]))
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in SPEC["per_layer"]])
+# Every committed per-layer metric, and every reader under ``metrics/``: the
+# train cell's two readers wait there for the cell that lists them.
+READERS = sorted({m["name"] for m in SPEC["per_layer"]}
+                 | {p.stem for p in (bench.HERE / "metrics").glob("*.py")})
+
+
+@pytest.mark.parametrize("metric", READERS)
 def test_metric_reader_finds_nothing_in_an_empty_record(metric):
     assert bench.metric_reader(metric)({}) is None
+
+
+def test_metrics_report_only_where_what_they_move_reports():
+    """A per-layer metric reports in no cell that lacks the end-to-end metric
+    it moves, and the collective cell reports none of the train metrics."""
+    cells = {m["name"]: set(m.get("workloads", WORKLOADS))
+             for m in SPEC["end_to_end"] + SPEC["per_layer"]}
+    for m in SPEC["per_layer"]:
+        assert cells[m["name"]] <= cells[m["moves"]], m["name"]
+    cell = bench.load_cell("noc-collectives.2x2")
+    reported = {m["name"] for m in cell.end_to_end + cell.per_layer}
+    assert not reported & {"train_tokens_per_s", "mfu.train", "idle_share.train"}

@@ -4,8 +4,9 @@ readers, the program's configuration) say what they state.
 
 No cell of ``BENCHMARK.json`` runs the train runner yet, so the tests build
 one here: the ``train-4k`` mix over the program's Qwen1.5 smoke configuration
-(float32), at 2 x 32 tokens a step.  The runs drive the whole of a run but the
-look for a chip.
+(float32), at 2 x 32 tokens a step, reporting the metrics as a train cell's
+entries in ``BENCHMARK.json`` will state them (``TRAIN_METRICS``).  The runs
+drive the whole of a run but the look for a chip.
 """
 
 import argparse
@@ -38,7 +39,23 @@ QWEN = dict(SMOKE, num_hidden_layers=24, hidden_size=1024, num_attention_heads=1
 # than 1e-2 on at least one (``test_train_control_reads_far_above_the_program``
 # prints the program's and the control's).
 LIMITS = {"loss_gap": 1e-4, "grad_gap": 1e-3, "grad_diff": 1e-2, "change_gap": 1e-2}
-METRIC = {"source": "host_clock", "better": "higher"}
+# The train cell's metrics, as its entries in ``BENCHMARK.json`` will state them.
+TRAIN_METRICS = {
+    "train_tokens_per_s": {"name": "train_tokens_per_s", "unit": "tokens/s",
+                           "better": "higher", "source": "host_clock"},
+    "mfu.train": {"name": "mfu.train", "unit": "%", "better": "higher",
+                  "source": "device_trace", "layer": "train step",
+                  "moves": "train_tokens_per_s"},
+    "idle_share.train": {"name": "idle_share.train", "unit": "%", "better": "lower",
+                         "source": "device_trace", "layer": "device",
+                         "moves": "train_tokens_per_s"},
+}
+
+
+def committed_setup_s() -> dict:
+    """``setup_s`` as ``BENCHMARK.json`` commits it; every cell reports it."""
+    return next(m for m in json.loads(bench.BENCHMARK.read_text())["end_to_end"]
+                if m["name"] == "setup_s")
 
 
 def smoke_cell():
@@ -47,10 +64,8 @@ def smoke_cell():
         name="qwen1.5-0.5b-smoke.train-4k", chips=1,
         config_name=SMOKE["name"], config=SMOKE,
         traffic_name="train-4k", traffic=dict(traffic, seq_len=32, trace_steps=1),
-        end_to_end=({"name": "setup_s", "unit": "s", **METRIC},
-                    {"name": "train_tokens_per_s", "unit": "tokens/s", **METRIC}),
-        per_layer=({"name": "mfu.train", "unit": "%", **METRIC},
-                   {"name": "idle_share.train", "unit": "%", **METRIC}))
+        end_to_end=(committed_setup_s(), TRAIN_METRICS["train_tokens_per_s"]),
+        per_layer=(TRAIN_METRICS["mfu.train"], TRAIN_METRICS["idle_share.train"]))
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +90,8 @@ def train_runs():
 def test_train_run_is_correct_when_nothing_is_broken(train_runs):
     r = train_runs["clean"]
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    assert {k: m["unit"] for k, m in r["metrics"].items()} == {"setup_s": "s",
+                                                               "train_tokens_per_s": "tokens/s"}
     assert r["metrics"]["train_tokens_per_s"]["value"] > 0
     assert set(r["checks"]) == {"loss_gap", "grad_gap", "grad_diff", "change_gap"}
 
@@ -142,3 +159,17 @@ def test_program_config_sets_only_what_the_file_lists():
     with pytest.raises(ValueError, match="only depth may be cut"):
         runner.program_config(dict(QWEN, reduced=["rope_theta"]),
                               dataclasses.replace(program, rope_theta=1e4))
+
+
+def test_notes_give_the_compiled_steps_memory():
+    import jax
+
+    cell = smoke_cell()
+    module = cell.runner()
+    runner = module.Runner(cell, SEED, jax.devices()[:1], jax, LIMITS)
+    runner.setup()
+    notes = runner.window(0.05)["notes"]
+    analysis = next(n for n in notes if n.startswith("the compiled step's memory_analysis"))
+    for figure in module.MEMORY_FIGURES:
+        assert f"'{figure}': " in analysis and f"'{figure}': None" not in analysis
+    assert any(n.startswith("device memory_stats after the window") for n in notes)
