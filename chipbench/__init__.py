@@ -10,6 +10,8 @@ Everything is found by name from ``BENCHMARK.json``: a configuration is
 ``configs/<config>.json`` (with its plain reference under ``reference/``), a
 traffic mix is ``traffic/<traffic>.json`` (read by the one runner its
 ``kind`` names, under ``runners/``), and a per-layer metric is
-``metrics/<metric>.py``.  The program under test is reached only through
-``repro.core.schedules``.
+``metrics/<metric>.py``.  The program under test is ``src/repro``: the
+collective runner calls ``repro.core.schedules``, and the train runner drives
+``repro.runtime.trainer.Trainer`` with its configuration from
+``repro.configs`` and its optimizer from ``repro.optim``.
 """

@@ -7,7 +7,8 @@ Set-up (process start to the first timed operation: loading, weights,
 compiling and warming every shape the cell uses) is ``setup_s``.  Then the
 cell's runner measures for ``--seconds`` with the profiler off
 (``--trace 0``: the cell's end-to-end metrics) or traces a short window
-(``--trace 1``: its per-layer metrics, read by ``metrics/<name>.py``).
+(``--trace 1``: its per-layer metrics, read by ``metrics/<name>.py``; a
+collective window also logs its call split and per-scope readings).
 After the window the program's state is freed and the runner compares what
 the timed path produced with the plain reference; each number compared is
 printed beside its limit, last on standard error and last in the result.
@@ -100,6 +101,28 @@ def result_metrics(cell, record: dict, setup_s: float, trace: bool) -> dict:
     return out
 
 
+def program_notes(trace_dir, record: dict) -> list[str]:
+    """Of a traced collective window: each call split into launch, program,
+    return and the time between calls on the aligned clock, and the device
+    time and collective ops per application of each schedule's scope
+    (``programs.py``).  Notes only, no metric."""
+    if not record.get("is_collective") or not record.get("attempted"):
+        return []
+    from chipbench import programs
+
+    trace = record["trace"]
+    try:
+        read = programs.read(trace_dir, trace["devices"])
+    except ValueError as e:  # the file's ops are not the ones extract kept
+        return [f"program readings: not read ({e})"]
+    per_program = record["applications"] // record["attempted"]
+    split = programs.call_split(trace, read["programs"], read["clock"])
+    scopes = programs.scope_readings(trace, read["scopes"], read["programs"], per_program,
+                                     record["is_collective"])
+    return ([f"call split, us per call on the aligned clock: {split}"]
+            + [f"scope {name}, per application: {r}" for name, r in scopes.items()])
+
+
 def run(args, cell=None, limits=None, require_chip: bool = True, runner_hook=None) -> dict:
     """One run; returns the result object.  ``cell``, ``limits``,
     ``require_chip=False`` and ``runner_hook`` (called with the runner before
@@ -143,6 +166,7 @@ def run(args, cell=None, limits=None, require_chip: bool = True, runner_hook=Non
         record["reduced"] = tr.reduce(record["trace"])
         device["busy_s"] = record["reduced"]["busy_s"]
         device["window_s"] = record["reduced"]["window_s"]
+        record.setdefault("notes", []).extend(program_notes(trace_dir, record))
         log(f"trace read in {time.perf_counter() - t0} s")
         shutil.rmtree(trace_dir, ignore_errors=True)
         LAST_TRACE.write_text(json.dumps({"trace": record["trace"], "reduced": record["reduced"]}))

@@ -3,9 +3,10 @@
 Each case (op, schedule, size) is one jitted ``shard_map`` program over the
 mesh axis whose body is a loop, with a trip count passed at run time, of
 ``calls_per_case`` applications of the op from ``repro.core.schedules``;
-each application takes the previous one's output.  The window cycles the
-cases round-robin, one program call at a time, each waited for, and every
-call carries its case's buffers into the next.  ``collective_us`` is the
+each application takes the previous one's output.  The trip count is put on
+the chips once, at set-up, so a call moves nothing from the host.  The
+window cycles the cases round-robin, one program call at a time, each
+waited for, and every call carries its case's buffers into the next.  ``collective_us`` is the
 window over all collective applications completed.
 
 After the window every case's buffers are compared, exactly, with the plain
@@ -78,6 +79,7 @@ class Runner:
                                   axis_types=(jax.sharding.AxisType.Auto,))
         sharding = NamedSharding(self.mesh, P(axis))
         self.k = int(tr["calls_per_case"])
+        self.k_on_chips = jax.device_put(np.int32(self.k), NamedSharding(self.mesh, P()))
         self.cases, self.fns, self.state, self.inputs = [], {}, {}, {}
         for op in tr["ops"]:
             for schedule in tr["schedules"]:
@@ -111,7 +113,7 @@ class Runner:
     def _call(self, case: str) -> None:
         t0 = time.perf_counter()
         with span("call"):
-            self.state[case] = self.fns[case](self.state[case], self.k)
+            self.state[case] = self.fns[case](self.state[case], self.k_on_chips)
             self.state[case].block_until_ready()
         self.case_s[case] += time.perf_counter() - t0
         self.calls[case] += 1

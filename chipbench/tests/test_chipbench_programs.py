@@ -112,6 +112,21 @@ def test_programs_and_clock_pair_executions_by_run_id():
     assert (clock["0"]["lo_ns"], clock["0"]["hi_ns"]) == (1200.0, 1900.0)
 
 
+def test_launch_us_from_each_call_to_its_first_launch():
+    # the window's calls [500, 3500] and [4600, 7600] first launch at 1200 and
+    # 4700 (a launch at 400 is before the call, one at 8000 after it); a call
+    # at 9800, cut by the window, does not count; 2 applications a call
+    t = dict(HAND, host=HAND["host"] + [["bench.call", 9800.0, 400.0]],
+             launches=[[400.0, 10.0, 1, 0], [1200.0, 10.0, 2, 0], [1250.0, 10.0, 2, 1],
+                       [4700.0, 10.0, 3, None], [8000.0, 10.0, None, None]])
+    metric = bench.metric_reader("launch_us")
+    assert metric({"trace": t, "applications": 4, "attempted": 2}) == pytest.approx(
+        1e-3 * (700 + 100) / 2 / 2)
+    assert metric({"trace": dict(t, launches=[[8000.0, 10.0, 1, 0]]), "applications": 4,
+                   "attempted": 2}) is None
+    assert metric({"trace": dict(t, launches=[]), "applications": 4, "attempted": 2}) is None
+
+
 def test_call_split_on_the_aligned_clock():
     clock = {"0": {"offset_ns": 300.0, "error_ns": 100.0}, "1": {"offset_ns": None}}
     progs = {"0": [[1000.0, 3000.0], [5000.0, 7000.0]], "1": [[5000.0, 7000.0]]}
@@ -177,15 +192,50 @@ def recorded():
     return json.loads(gzip.decompress((DATA / f"{RECORDED}.json.gz").read_bytes()))
 
 
-def test_recorded_trace_reads_as_it_did_on_the_chip(recorded, tmp_path):
-    """The raw trace of one round of the 12 (op, schedule) pairs on a v5e
-    2x2, and what ``extract`` and ``read`` kept from it then."""
-    (tmp_path / "t.xplane.pb").write_bytes(
+@pytest.fixture(scope="module")
+def raw(tmp_path_factory):
+    """The directory of the recorded raw trace, and what ``extract`` keeps of it."""
+    path = tmp_path_factory.mktemp("recorded")
+    (path / "t.xplane.pb").write_bytes(
         gzip.decompress((DATA / f"{RECORDED}.xplane.pb.gz").read_bytes()))
-    kept = trace.extract(tmp_path)
-    assert kept == recorded["trace"]
-    new = programs.read(tmp_path, kept["devices"])
+    return path, trace.extract(path)
+
+
+def test_recorded_trace_reads_as_it_did_on_the_chip(recorded, raw):
+    """The raw trace of one round of the 12 (op, schedule) pairs on a v5e
+    2x2, and what ``extract`` and ``read`` kept from it then; the program
+    launches, which ``extract`` keeps since, are 4 a call, one per chip."""
+    path, kept = raw
+    assert {k: kept[k] for k in recorded["trace"]} == recorded["trace"]
+    new = programs.read(path, kept["devices"])
     assert new == {k: recorded[k] for k in ("scopes", "programs", "clock")}
+    launches = kept["launches"]
+    assert len(launches) == 4 * recorded["readings"]["calls"]
+    assert sorted({launch[3] for launch in launches}) == [0, 1, 2, 3]
+    assert launches == sorted(launches, key=lambda launch: launch[0])
+    assert launches[0] == [151354466.0, 37320.0, 13, 0]
+
+
+def test_launch_us_on_the_recorded_trace(recorded, raw):
+    """From each call's start to its first launch: 765.4 us a call, at 8
+    applications a call; the parent of the change that put the trip count
+    on the chips once, whose every call put it there from the host."""
+    kept, k, calls = raw[1], recorded["per_program"], recorded["readings"]["calls"]
+    metric = bench.metric_reader("launch_us")
+    us = metric({"trace": kept, "applications": calls * k, "attempted": calls})
+    assert us == 95.67032291666668
+    assert metric({"trace": recorded["trace"], "applications": calls * k,
+                   "attempted": calls}) is None  # recorded before launches were kept
+
+
+def test_recorded_idle_gaps_are_named_by_harness_spans(raw):
+    """The launches ``extract`` keeps name no idle gap: only ``bench.*`` spans do."""
+    kept = raw[1]
+    assert all(name.startswith("bench.") for name, _, _ in kept["host"])
+    gaps = trace.reduce(kept)["breakdown"]["idle_gaps"]
+    assert gaps and {name for name, _ in gaps} <= {"bench.call", "bench.round", trace.NO_SPAN}
+    without = trace.reduce({k: v for k, v in kept.items() if k != "launches"})
+    assert without["breakdown"] == trace.reduce(kept)["breakdown"]
 
 
 def test_recorded_trace_readings(recorded):
@@ -227,3 +277,21 @@ def test_recorded_trace_scopes(recorded):
             if not any(sc for _, sc in held):
                 unscoped.append({op[0].split()[0] for op, _ in held if is_coll(op[0])})
         assert unscoped == [{"all-reduce"}]
+
+
+def test_traced_run_notes_the_call_split_and_scopes(recorded, raw):
+    """A traced collective run logs what ``programs.py`` reads from its trace:
+    the call split and each scope's readings, as they were read on the chip."""
+    from chipbench import run as bench_run
+
+    path, kept = raw
+    k, calls = recorded["per_program"], recorded["readings"]["calls"]
+    is_coll = bench.load_cell("noc-collectives.2x2").runner().is_collective
+    record = {"trace": kept, "applications": calls * k, "attempted": calls,
+              "is_collective": is_coll}
+    notes = bench_run.program_notes(path, record)
+    was = recorded["readings"]
+    assert notes[0] == f"call split, us per call on the aligned clock: {was['split']}"
+    assert notes[1:] == [f"scope {name}, per application: {r}"
+                         for name, r in was["scopes"].items()]
+    assert bench_run.program_notes(path, dict(record, is_collective=None)) == []

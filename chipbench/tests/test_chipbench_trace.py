@@ -3,6 +3,7 @@
 import gzip
 import json
 import pathlib
+from types import SimpleNamespace as NS
 
 import pytest
 
@@ -78,3 +79,15 @@ def test_op_names_drop_layouts_and_operands():
             "kind=kOutput, calls=%fused_computation.533")
     assert trace.op_name(text) == "fusion fusion.623 (f32[2,16,4096], f32[2,16,4096,4096])"
     assert trace.op_name("all-reduce.1") == "all-reduce.1"
+
+
+def test_a_launch_keeps_the_ids_its_stats_give():
+    event = NS(start_ns=10, duration_ns=2, stats=[("run_id", 7), ("queue_id", 0)])
+    assert trace._launch(event) == [10.0, 2.0, 7, None]
+    event = NS(start_ns=10, duration_ns=2, stats=[("device_ordinal", 3), ("run_id", 8)])
+    assert trace._launch(event) == [10.0, 2.0, 8, 3]
+
+
+def test_launches_name_no_idle_gap():
+    with_launches = dict(HAND, launches=[[3.1e8, 1e6, 1, 0], [7e8, 1e6, 2, 0]])
+    assert trace.reduce(with_launches) == trace.reduce(HAND)

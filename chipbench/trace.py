@@ -2,8 +2,9 @@
 
 ``extract`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote and keeps
 only what the reduction needs, as plain data: the operations each TPU ran
-(line ``XLA Ops`` of each ``/device:TPU:<n>`` plane) and the harness's own
-host spans (``bench.*``, see ``clock.span``).  ``reduce`` turns that into
+(line ``XLA Ops`` of each ``/device:TPU:<n>`` plane), the harness's own
+host spans (``bench.*``, see ``clock.span``) and the runtime's program
+launches on the host (``DoEnqueueProgram``).  ``reduce`` turns that into
 busy time, the idle share, the operations that took the most device time and
 the idle gaps named by the innermost harness span open in them.  The
 reduction works on the plain data, so a small recorded trace tests it.
@@ -21,6 +22,7 @@ from chipbench.clock import SPAN_PREFIX
 WINDOW_SPAN = SPAN_PREFIX + "window"
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+LAUNCH_EVENT = "DoEnqueueProgram"
 NO_SPAN = "no harness span"
 
 
@@ -45,7 +47,7 @@ def extract(trace_dir: str | pathlib.Path) -> dict:
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     data = ProfileData.from_file(str(files[-1]))
-    devices, host = {}, []
+    devices, host, launches = {}, [], []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE):
             dev = plane.name[len(DEVICE_PLANE):]
@@ -53,14 +55,26 @@ def extract(trace_dir: str | pathlib.Path) -> dict:
                    for line in plane.lines if line.name == OPS_LINE for e in line.events]
             devices[dev] = ops
         elif plane.name.startswith("/host:"):
-            host += [[e.name, float(e.start_ns), float(e.duration_ns)]
-                     for line in plane.lines for e in line.events
-                     if e.name.startswith(SPAN_PREFIX)]
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        host.append([e.name, float(e.start_ns), float(e.duration_ns)])
+                    elif e.name == LAUNCH_EVENT:
+                        launches.append(_launch(e))
     windows = [h for h in host if h[0] == WINDOW_SPAN]
     if not windows:
         raise ValueError(f"the trace holds no {WINDOW_SPAN} span")
     w = windows[-1]
-    return {"window": [w[1], w[1] + w[2]], "devices": devices, "host": host}
+    return {"window": [w[1], w[1] + w[2]], "devices": devices, "host": host,
+            "launches": sorted(launches, key=lambda launch: launch[0])}
+
+
+def _launch(event) -> list:
+    """[start, duration, run_id, device_ordinal] of a program launch; an id
+    the event's stats do not give is None."""
+    stats = dict(event.stats)
+    ids = [None if stats.get(k) is None else int(stats[k]) for k in ("run_id", "device_ordinal")]
+    return [float(event.start_ns), float(event.duration_ns), *ids]
 
 
 def _merge(intervals):
